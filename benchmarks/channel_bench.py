@@ -28,6 +28,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 try:  # harness mode (python -m benchmarks.run) vs script mode
     from benchmarks import stream_bench
 except ImportError:  # script mode: benchmarks/ itself is sys.path[0]
@@ -136,6 +138,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true", help="larger N/T protocol")
     args = ap.parse_args()
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())} ({jax.default_backend()})")
     print("name,us_per_call,derived")
     for r in run(quick=not args.full):

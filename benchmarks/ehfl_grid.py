@@ -33,6 +33,7 @@ from typing import Sequence
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.cifar_cnn import CNNConfig
 from repro.core import SCENARIOS, EHFLConfig, run_batch
 from repro.data import make_federated_dataset
@@ -192,6 +193,7 @@ def main() -> None:
     ap.add_argument("--policy", default="vaoi", choices=POLICIES)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     quick = args.quick
 
     cells, st = run_scenarios(quick, args.seed, args.policy)

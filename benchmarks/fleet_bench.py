@@ -34,6 +34,8 @@ if __name__ == "__main__" and "jax" not in sys.modules:
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
+
 ROOT = Path(__file__).resolve().parent.parent
 OUT = ROOT / "BENCH_fleet.json"
 
@@ -128,6 +130,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true", help="sweep N up to 64k")
     args = ap.parse_args()
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())} ({jax.default_backend()})")
     print("name,us_per_call,derived")
     for r in run(quick=not args.full):

@@ -21,6 +21,8 @@ import sys
 import threading
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def _watchdog(suite: str, timeout_s: float) -> threading.Timer:
     """Arm a wall-clock kill switch for one suite.  ``os._exit`` (not
@@ -54,6 +56,7 @@ def main() -> None:
         "fails the harness (exit 1) instead of hanging",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     quick = not args.full
 
     from benchmarks import (

@@ -22,6 +22,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 ROOT = Path(__file__).resolve().parent.parent
 OUT = ROOT / "BENCH_stream.json"
 
@@ -132,6 +134,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true", help="larger N/T protocol")
     args = ap.parse_args()
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())} ({jax.default_backend()})")
     print("name,us_per_call,derived")
     for r in run(quick=not args.full):

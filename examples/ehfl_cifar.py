@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import save_pytree
+from repro.compile_cache import enable_compile_cache
 from repro.configs.cifar_cnn import CONFIG as PAPER_CNN
 from repro.configs.cifar_cnn import CNNConfig
 from repro.core import (
@@ -34,7 +35,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="vaoi",
                     choices=["vaoi", "fedavg", "fedbacys", "fedbacys_odd"])
-    ap.add_argument("--rounds", type=int, default=200)
+    ap.add_argument("--rounds", type=int, default=None,
+                    help="global epochs T (default 200; 500 under --paper-scale)")
     ap.add_argument("--clients", type=int, default=30)
     ap.add_argument("--samples", type=int, default=100)
     ap.add_argument("--alpha", type=float, default=0.1)
@@ -66,17 +68,21 @@ def main() -> None:
                          "visible devices; virtualize CPU devices with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=8")
     ap.add_argument("--paper-scale", action="store_true",
-                    help="full paper protocol: N=100, T=500, 300 samples, 32px CNN")
+                    help="full paper protocol: N=100, T=500 (unless --rounds), "
+                         "300 samples, 32px CNN")
     ap.add_argument("--out", default="experiments/ehfl_cifar")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fleet and args.num_seeds > 1:
         ap.error("--fleet runs a single seed; drop --num-seeds "
                  "(seed sweeps go through run_batch, fleets through run_fleet)")
 
     if args.paper_scale:
-        args.clients, args.rounds, args.samples, args.k = 100, 500, 300, 10
+        args.clients, args.samples, args.k = 100, 300, 10
+        args.rounds = args.rounds or 500
         cnn, image = PAPER_CNN, 32
     else:
+        args.rounds = args.rounds or 200
         cnn = CNNConfig(name="driver", image_size=16,
                         conv_channels=(16, 16, 32, 32, 64, 64), fc_dims=(128, 64))
         image = 16

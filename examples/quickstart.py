@@ -6,11 +6,13 @@ then the harvest-scenario gallery through the seed-vmapped sweep engine.
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.cifar_cnn import CNNConfig
 from repro.core import SCENARIOS, EHFLConfig, run_batch, run_simulation
 from repro.data import make_federated_dataset
 from repro.fl import cnn_backend
 
+enable_compile_cache()
 cnn = CNNConfig(name="quick", image_size=16, conv_channels=(8, 8, 16, 16, 32, 32), fc_dims=(64, 32))
 data = make_federated_dataset(
     jax.random.PRNGKey(0), num_clients=12, samples_per_client=60,

@@ -35,7 +35,6 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import channel as channel_lib
@@ -203,14 +202,14 @@ def fleet_program(
     # copies per device); the data/ts args are reused across eval_every
     # chunks, so they are deliberately NOT donated
     scan_chunk = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda c, ts, images, labels: jax.lax.scan(
                 lambda cc, t: epoch_fn(cc, t, images, labels), c, ts
             ),
             mesh=mesh,
             in_specs=(specs, rep, cl, cl),
             out_specs=(specs, rep),
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0,),
     )

@@ -34,6 +34,13 @@ from repro.core import vaoi as vaoi_lib
 from repro.data import stream as stream_lib
 from repro.optim import sgd_update
 
+# The VAoI probe forward (Eq. 5) runs this many clients per step of a loop
+# over the client axis.  One conv batch of all N * probe_size images (2000 at
+# the paper's N=100, probe 20, with the 32 px CNN) overflows the stack of the
+# TPU compiler's conv-fusion cost model (libtpu 0.0.34) and kills the process
+# at compile time; 10 clients per step compiles at every paper-width shape.
+PROBE_CLIENTS_PER_STEP = 10
+
 
 @dataclass(frozen=True)
 class EHFLConfig:
@@ -397,7 +404,10 @@ def epoch_body(
     # --- CLIENTSELECT (Alg. 2) on the freshly-broadcast global model ---
     selected = ops.select(spec, carry.age, t, cfg.k, k_sel)
     if spec.uses_vaoi:
-        v = jax.vmap(lambda imgs: backend.feature(carry.global_params, imgs))(probe_imgs)
+        v = jax.lax.map(
+            lambda imgs: backend.feature(carry.global_params, imgs),
+            probe_imgs, batch_size=min(PROBE_CLIENTS_PER_STEP, n_loc),
+        )
         if use_kernel:  # fused Pallas kernel (Eq. 5 + Eq. 7 in one pass)
             from repro.kernels import ops as kops
 
@@ -644,13 +654,23 @@ def run_simulation(
     use_kernel: bool = False,
 ) -> Dict[str, Any]:
     """Run T epochs of Alg. 1. Returns metric trajectories + final model."""
-    epoch_fn = make_epoch_fn(cfg, backend, data, use_kernel=use_kernel)
-    # the carry is donated: msg_params is N stacked model copies, and
-    # without donation every eval_every chunk allocates a fresh copy
-    scan_chunk = jax.jit(
-        lambda c, ts: jax.lax.scan(epoch_fn, c, ts), donate_argnums=(0,)
+
+    # the client data enters as jit ARGUMENTS: a closed-over array is baked
+    # into the program as a constant, which at paper width (~370 MB of
+    # client images) swamps compilation.  The carry is donated:
+    # msg_params is N stacked model copies, and without donation every
+    # eval_every chunk allocates a fresh copy.
+    def chunk(c, ts, images, labels):
+        epoch_fn = make_epoch_fn(
+            cfg, backend, {"images": images, "labels": labels}, use_kernel=use_kernel
+        )
+        return jax.lax.scan(epoch_fn, c, ts)
+
+    scan_chunk = jax.jit(chunk, donate_argnums=(0,))
+    return drive_epochs(
+        lambda c, ts: scan_chunk(c, ts, data["images"], data["labels"]),
+        init_carry(cfg, backend), cfg, backend, data,
     )
-    return drive_epochs(scan_chunk, init_carry(cfg, backend), cfg, backend, data)
 
 
 def run_batch(
@@ -675,45 +695,52 @@ def run_batch(
     across seeds and stays 1-D ``(n_evals,)``.
     """
     seeds = jnp.asarray(seeds, jnp.int32)
-    epoch_fn = make_epoch_fn(cfg, backend, data, use_kernel=use_kernel)
     from repro.models.cnn import macro_f1
 
     chunk = max(1, cfg.eval_every)
     n_full, rem = divmod(cfg.epochs, chunk)
+    # the data is a jit argument shared across the seed axis, not a
+    # closed-over constant (see run_simulation)
+    data = {k: data[k] for k in ("images", "labels", "test_images", "test_labels")}
 
-    def eval_f1(params):
-        preds = backend.predict(params, data["test_images"])
-        return macro_f1(preds, data["test_labels"], backend.num_classes)
+    def sweep(seeds, data):
+        epoch_fn = make_epoch_fn(cfg, backend, data, use_kernel=use_kernel)
 
-    def solo(seed):
-        carry = init_carry(cfg, backend, seed)
-        ms_parts, f1_parts = [], []
-        if n_full:
-            def chunk_body(c, i):
-                c, ms = jax.lax.scan(epoch_fn, c, i * chunk + jnp.arange(chunk))
-                return c, (ms, eval_f1(c.global_params))
+        def eval_f1(params):
+            preds = backend.predict(params, data["test_images"])
+            return macro_f1(preds, data["test_labels"], backend.num_classes)
 
-            carry, (ms, f1s) = jax.lax.scan(chunk_body, carry, jnp.arange(n_full))
-            ms_parts.append(
-                jax.tree.map(lambda x: x.reshape((n_full * chunk,) + x.shape[2:]), ms)
+        def solo(seed):
+            carry = init_carry(cfg, backend, seed)
+            ms_parts, f1_parts = [], []
+            if n_full:
+                def chunk_body(c, i):
+                    c, ms = jax.lax.scan(epoch_fn, c, i * chunk + jnp.arange(chunk))
+                    return c, (ms, eval_f1(c.global_params))
+
+                carry, (ms, f1s) = jax.lax.scan(chunk_body, carry, jnp.arange(n_full))
+                ms_parts.append(
+                    jax.tree.map(lambda x: x.reshape((n_full * chunk,) + x.shape[2:]), ms)
+                )
+                f1_parts.append(f1s)
+            if rem:
+                carry, ms_tail = jax.lax.scan(
+                    epoch_fn, carry, jnp.arange(n_full * chunk, cfg.epochs)
+                )
+                ms_parts.append(ms_tail)
+                f1_parts.append(eval_f1(carry.global_params)[None])
+            metrics = (
+                jax.tree.map(lambda *xs: jnp.concatenate(xs), *ms_parts)
+                if len(ms_parts) > 1
+                else ms_parts[0]
             )
-            f1_parts.append(f1s)
-        if rem:
-            carry, ms_tail = jax.lax.scan(
-                epoch_fn, carry, jnp.arange(n_full * chunk, cfg.epochs)
-            )
-            ms_parts.append(ms_tail)
-            f1_parts.append(eval_f1(carry.global_params)[None])
-        metrics = (
-            jax.tree.map(lambda *xs: jnp.concatenate(xs), *ms_parts)
-            if len(ms_parts) > 1
-            else ms_parts[0]
-        )
-        metrics = dict(metrics)
-        metrics["f1"] = jnp.concatenate(f1_parts) if len(f1_parts) > 1 else f1_parts[0]
-        return carry, metrics
+            metrics = dict(metrics)
+            metrics["f1"] = jnp.concatenate(f1_parts) if len(f1_parts) > 1 else f1_parts[0]
+            return carry, metrics
 
-    carries, metrics = jax.jit(jax.vmap(solo))(seeds)
+        return jax.vmap(solo)(seeds)
+
+    carries, metrics = jax.jit(sweep)(seeds, data)
     metrics["f1_epochs"] = jnp.asarray(
         [chunk * (i + 1) for i in range(n_full)] + ([cfg.epochs] if rem else [])
     )

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode — the
-kernel body runs in Python with real BlockSpec tiling semantics, which is how
-we validate them against the ``ref.py`` oracles.  On TPU they compile to
-Mosaic.
+Off the TPU (the CPU test path) the kernels execute in ``interpret=True``
+mode — the kernel body runs in Python with real BlockSpec tiling semantics,
+which is how they are validated against the ``ref.py`` oracles.  On a TPU
+backend they always compile to Mosaic.
 """
 from __future__ import annotations
 
@@ -25,16 +25,10 @@ def vaoi_distance(v, h, age, q, mu, **kw):
     return _vaoi_distance(v, h, age, q, mu, **kw)
 
 
-def vaoi_update(age, m_unused, q, mu):
-    """Deprecated shim kept for the simulator's kernel path; prefer
-    vaoi_distance which fuses the distance."""
-    raise NotImplementedError("use vaoi_distance(v, h, age, q, mu)")
-
-
 def fedavg_reduce(msgs, weights, **kw):
     """Weighted (K, P) -> (P,) reduce.  K may be the full client axis N or
-    the compacted ``cap``-sized training slab (DESIGN.md §11); the kernel
-    pads small K up to the sublane multiple, so slab calls stay aligned."""
+    the compacted ``cap``-sized training slab (DESIGN.md §11); a K that
+    fits one block is taken whole, so slab calls need no alignment."""
     kw.setdefault("interpret", _interpret())
     return _fedavg_reduce(msgs, weights, **kw)
 

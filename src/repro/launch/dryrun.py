@@ -176,8 +176,6 @@ def run_one(
     # --- cost ---
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns one dict per program
-            ca = ca[0] if ca else {}
         flops = float(ca.get("flops", 0.0))
         hbm = float(ca.get("bytes accessed", 0.0))
         rec["cost"] = {"flops": flops, "bytes_accessed": hbm}

@@ -5,23 +5,35 @@ Multi-pod:   (2, 16, 16) -> axes ("pod", "data", "model") = 512 chips
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; tests see 1 CPU).
+
+Every mesh here uses ``Auto`` axes: the sharding rules of
+``launch/sharding.py`` are GSPMD hints, not sharding-in-types.  Under
+``jax.make_mesh``'s default ``Explicit`` axes the embedding gather of a
+batch-sharded token array against the FSDP table (``V->model, d->data``)
+would need ``data`` on two output dims and is refused.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Tiny mesh over whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     dp = n // model_parallel
-    return jax.make_mesh((dp, model_parallel), ("data", "model"))
+    return _mesh((dp, model_parallel), ("data", "model"))
 
 
 def make_fleet_mesh(num_shards: int | None = None, *, num_clients: int | None = None):
@@ -35,7 +47,7 @@ def make_fleet_mesh(num_shards: int | None = None, *, num_clients: int | None = 
         n = min(n, num_clients)
         while num_clients % n:
             n -= 1
-    return jax.make_mesh((n,), ("data",))
+    return _mesh((n,), ("data",))
 
 
 def data_axes(mesh) -> tuple:

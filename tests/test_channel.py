@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.cifar_cnn import CNNConfig
@@ -368,7 +368,7 @@ def test_sharded_channel_matches_global(scenario, params, rng):
     got = jax.jit(
         shard_map(
             lambda a: roll(shp, a), mesh=mesh, in_specs=P(None, "data"),
-            out_specs=P(None, "data"), check_rep=False,
+            out_specs=P(None, "data"), check_vma=False,
         )
     )(atts)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got), err_msg=scenario)

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.cifar_cnn import CNNConfig
@@ -181,7 +181,7 @@ def test_sharded_harvest_matches_global(scenario):
     got = jax.jit(
         shard_map(
             lambda b: roll(shp, b), mesh=mesh, in_specs=P("data"),
-            out_specs=P(None, "data"), check_rep=False,
+            out_specs=P(None, "data"), check_vma=False,
         )
     )(battery)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got), err_msg=scenario)
@@ -204,7 +204,7 @@ def test_distributed_topk_matches_global(k, rng):
                 lambda a: vaoi_lib.select_topk_sharded(
                     a, k, key, axis_name="data", n_global=n
                 ),
-                mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False,
+                mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False,
             )
         )(age)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
@@ -225,7 +225,7 @@ def test_epoch_selection_sharded_matches_global(policy, rng):
                 lambda a: policy_lib.epoch_selection_sharded(
                     spec, a, epoch, k, key, axis_name="data", n_global=n
                 ),
-                mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False,
+                mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False,
             )
         )(age)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got), err_msg=policy)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.cifar_cnn import CNNConfig
@@ -263,7 +263,7 @@ def test_sharded_stream_matches_solo(scenario):
     got = jax.jit(
         shard_map(
             lambda l: roll(shp, l), mesh=mesh, in_specs=P("data", None),
-            out_specs=P(None, "data", None), check_rep=False,
+            out_specs=P(None, "data", None), check_vma=False,
         )
     )(labels)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got), err_msg=scenario)
