@@ -58,7 +58,6 @@ EXACT_METRICS = (
     "avg_age", "f1_epochs",
 )
 EXACT_CARRY = ("age", "battery", "pending", "counter", "retries", "backoff")
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class SmokeFailure(AssertionError):
@@ -93,26 +92,15 @@ def paper_setup(num_clients: int, epochs: int):
     return cfg, cnn_backend(CONFIG), data
 
 
-_compile_s = [0.0]  # backend compile seconds so far, from JAX's own events
-
-
-def _on_duration(event: str, duration: float, **_) -> None:
-    if event == _COMPILE_EVENT:
-        _compile_s[0] += duration
-
-
 def run_phase(name: str, fn) -> dict:
     """Run ``fn`` (one driver call), wait for the device, print the phase
-    line: compile seconds, and the rest of the host-clock wall time per
-    epoch."""
+    line.  Speed is the benchmark's to measure (``bench/run.py``), not this
+    script's."""
     import jax
     import numpy as np
 
-    c0, t0 = _compile_s[0], time.perf_counter()
     out = fn()
     jax.block_until_ready(out)
-    wall = time.perf_counter() - t0
-    compile_s = _compile_s[0] - c0
     _check_finite(name, out)
     m = out["metrics"]
     dev = jax.devices()[0]
@@ -120,8 +108,6 @@ def run_phase(name: str, fn) -> dict:
         "phase": name,
         "device_kind": dev.device_kind,
         "device_count": len(jax.devices()),
-        "compile_s": compile_s,
-        "steady_s_per_epoch": (wall - compile_s) / np.shape(m["n_started"])[-1],
         "f1_last": np.asarray(m["f1"])[..., -1].tolist(),
         "n_started_total": int(np.asarray(m["n_started"]).sum()),
         "peak_bytes_in_use": (dev.memory_stats() or {}).get("peak_bytes_in_use"),
@@ -280,7 +266,6 @@ def main() -> int:
 
     enable_compile_cache()
     _require(not ops._interpret(), "Pallas kernels would run in interpret mode on the TPU")
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     cache = Path(jax.config.jax_compilation_cache_dir)
     cache_entries = len(list(cache.iterdir())) if cache.is_dir() else 0
 

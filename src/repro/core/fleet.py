@@ -52,6 +52,7 @@ from repro.core.simulator import (
     drive_epochs,
     epoch_body,
     init_carry,
+    tracing_chunk,
 )
 
 AXIS = "data"  # the client/fleet mesh axis
@@ -194,18 +195,21 @@ def fleet_program(
     carry_shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), specs, is_leaf=lambda x: isinstance(x, P)
     )
-    carry0 = jax.jit(
-        lambda: init_carry(cfg, backend), out_shardings=carry_shardings
-    )()
+    with jax.profiler.TraceAnnotation("ehfl.init_carry"):
+        carry0 = jax.jit(
+            lambda: init_carry(cfg, backend), out_shardings=carry_shardings
+        )()
+
+    def chunk(c, ts, images, labels):
+        with tracing_chunk():
+            return jax.lax.scan(lambda cc, t: epoch_fn(cc, t, images, labels), c, ts)
 
     # the carry is donated (its msg_params shard is still N_loc model
     # copies per device); the data/ts args are reused across eval_every
     # chunks, so they are deliberately NOT donated
     scan_chunk = jax.jit(
         jax.shard_map(
-            lambda c, ts, images, labels: jax.lax.scan(
-                lambda cc, t: epoch_fn(cc, t, images, labels), c, ts
-            ),
+            chunk,
             mesh=mesh,
             in_specs=(specs, rep, cl, cl),
             out_specs=(specs, rep),

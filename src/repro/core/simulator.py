@@ -19,6 +19,7 @@ runs a full multi-seed sweep cell as ONE jitted call (DESIGN.md §8).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, NamedTuple, Sequence, Tuple
@@ -40,6 +41,26 @@ from repro.optim import sgd_update
 # TPU compiler's conv-fusion cost model (libtpu 0.0.34) and kills the process
 # at compile time; 10 clients per step compiles at every paper-width shape.
 PROBE_CLIENTS_PER_STEP = 10
+
+# Tracing (README "Tracing").  ``run_simulation`` and ``fleet_program`` open
+# host spans (``jax.profiler.TraceAnnotation``: ``ehfl.init_carry``,
+# ``ehfl.chunk``, ``ehfl.trace_chunk``, ``ehfl.eval``) on the Python thread,
+# and count every trace of the epoch program as a ``jax.monitoring`` event;
+# the epoch body names its phases with ``jax.named_scope``
+# (``ehfl.vaoi_proxy``, ``ehfl.slot_scan``, ``ehfl.local_train``,
+# ``ehfl.eq6_moment``, ``ehfl.fedavg``), which reach a device trace as each
+# op's ``tf_op`` path and leave the compiled program as it is.
+CHUNK_TRACE_EVENT = "/ehfl/drivers/chunk_trace"
+
+
+@contextlib.contextmanager
+def tracing_chunk():
+    """Wrap the body of the jitted chunk function of ``run_simulation`` or
+    ``fleet_program``: it runs only while JAX traces the chunk, so the span
+    and the event mark each retrace."""
+    jax.monitoring.record_event(CHUNK_TRACE_EVENT)
+    with jax.profiler.TraceAnnotation("ehfl.trace_chunk"):
+        yield
 
 
 @dataclass(frozen=True)
@@ -169,8 +190,9 @@ def _local_train(
         _, grads = backend.grad_loss(params, imgs, lbls)
         params = sgd_update(params, grads, cfg.lr)
         if with_feature:
-            f = backend.feature(params, imgs)  # batch-mean feature of w^(t,b+1)
-            fsum = fsum + f * bs
+            with jax.named_scope("ehfl.eq6_moment"):
+                f = backend.feature(params, imgs)  # batch-mean feature of w^(t,b+1)
+                fsum = fsum + f * bs
         return (params, fsum), None
 
     fsum0 = jnp.zeros((backend.feature_dim,), jnp.float32) if with_feature else None
@@ -404,46 +426,48 @@ def epoch_body(
     # --- CLIENTSELECT (Alg. 2) on the freshly-broadcast global model ---
     selected = ops.select(spec, carry.age, t, cfg.k, k_sel)
     if spec.uses_vaoi:
-        v = jax.lax.map(
-            lambda imgs: backend.feature(carry.global_params, imgs),
-            probe_imgs, batch_size=min(PROBE_CLIENTS_PER_STEP, n_loc),
-        )
-        if use_kernel:  # fused Pallas kernel (Eq. 5 + Eq. 7 in one pass)
-            from repro.kernels import ops as kops
-
-            m, age = kops.vaoi_distance(
-                v, carry.h, carry.age, selected.astype(jnp.float32), cfg.mu
+        with jax.named_scope("ehfl.vaoi_proxy"):
+            v = jax.lax.map(
+                lambda imgs: backend.feature(carry.global_params, imgs),
+                probe_imgs, batch_size=min(PROBE_CLIENTS_PER_STEP, n_loc),
             )
-        else:
-            m = vaoi_lib.feature_distance(v, carry.h)
-            age = vaoi_lib.vaoi_update(carry.age, m, selected.astype(jnp.float32), cfg.mu)
+            if use_kernel:  # fused Pallas kernel (Eq. 5 + Eq. 7 in one pass)
+                from repro.kernels import ops as kops
+
+                m, age = kops.vaoi_distance(
+                    v, carry.h, carry.age, selected.astype(jnp.float32), cfg.mu
+                )
+            else:
+                m = vaoi_lib.feature_distance(v, carry.h)
+                age = vaoi_lib.vaoi_update(carry.age, m, selected.astype(jnp.float32), cfg.mu)
     else:
         age = carry.age
         m = jnp.zeros((n_loc,), jnp.float32)
 
     # --- slot-level energy dynamics ---
-    want_fn = policy_lib.make_want_fn(spec, selected, S, kappa)
-    opp_fn = policy_lib.make_opportunity_fn(spec, selected, S, kappa)
-    st0 = energy_lib.SlotState(
-        battery=carry.battery,
-        started=jnp.zeros((n_loc,), bool),
-        start_slot=jnp.full((n_loc,), S, jnp.int32),
-        pending=carry.pending,
-        uploaded=jnp.zeros((n_loc,), bool),
-        counter=carry.counter,
-        energy_used=jnp.zeros((n_loc,), jnp.int32),
-        key=k_scan,
-        harvest=carry.harvest,  # None -> re-seeded from k_scan in scan_epoch
-        stream=stream_state,  # rides the slot scan untouched (hook for
-        # slot-granular arrival processes; per-epoch streams step above)
-    )
-    st = energy_lib.scan_epoch(
-        st0, S=S, kappa=kappa, e_max=cfg.e_max, process=process,
-        want_fn=want_fn, count_opportunity_fn=opp_fn,
-        # retry backoff gates transmission for the whole epoch (the pending
-        # message — and its energy — is held, not re-contended)
-        tx_allowed=(carry.backoff == 0) if channel is not None else None,
-    )
+    with jax.named_scope("ehfl.slot_scan"):
+        want_fn = policy_lib.make_want_fn(spec, selected, S, kappa)
+        opp_fn = policy_lib.make_opportunity_fn(spec, selected, S, kappa)
+        st0 = energy_lib.SlotState(
+            battery=carry.battery,
+            started=jnp.zeros((n_loc,), bool),
+            start_slot=jnp.full((n_loc,), S, jnp.int32),
+            pending=carry.pending,
+            uploaded=jnp.zeros((n_loc,), bool),
+            counter=carry.counter,
+            energy_used=jnp.zeros((n_loc,), jnp.int32),
+            key=k_scan,
+            harvest=carry.harvest,  # None -> re-seeded from k_scan in scan_epoch
+            stream=stream_state,  # rides the slot scan untouched (hook for
+            # slot-granular arrival processes; per-epoch streams step above)
+        )
+        st = energy_lib.scan_epoch(
+            st0, S=S, kappa=kappa, e_max=cfg.e_max, process=process,
+            want_fn=want_fn, count_opportunity_fn=opp_fn,
+            # retry backoff gates transmission for the whole epoch (the pending
+            # message — and its energy — is held, not re-contended)
+            tx_allowed=(carry.backoff == 0) if channel is not None else None,
+        )
 
     # --- uplink channel + retry state machine (DESIGN.md §12) ---
     # ``st.uploaded`` clients SPENT a transmission unit; the channel decides
@@ -482,7 +506,8 @@ def epoch_body(
 
     # --- local training (only VAoI policies read the Eq. 6 moment h) ---
     pending_in = carry.pending  # entered the epoch with an unsent (old) message?
-    train_keys = ops.train_keys(k_train, n_loc)
+    with jax.named_scope("ehfl.local_train"):
+        train_keys = ops.train_keys(k_train, n_loc)
     cap = resolve_compact_cap(cfg, spec)
     train_one = lambda imgs, lbls, k: _local_train(
         carry.global_params, imgs, lbls, k, cfg, backend, with_feature=spec.uses_vaoi
@@ -490,24 +515,27 @@ def epoch_body(
 
     if cap is None:
         # --- dense path: vmap over all clients, mask by st.started ---
-        trained, h_new = jax.vmap(train_one)(images, labels, train_keys)
-        started_m = st.started
-        sel = lambda new, old: jax.tree.map(
-            lambda a, b: jnp.where(started_m.reshape((-1,) + (1,) * (a.ndim - 1)), a, b), new, old
-        )
-        msg_params = sel(trained, carry.msg_params)
-        h = jnp.where(started_m[:, None], h_new, carry.h) if spec.uses_vaoi else carry.h
+        with jax.named_scope("ehfl.local_train"):
+            trained, h_new = jax.vmap(train_one)(images, labels, train_keys)
+            started_m = st.started
+            sel = lambda new, old: jax.tree.map(
+                lambda a, b: jnp.where(started_m.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
+                new, old,
+            )
+            msg_params = sel(trained, carry.msg_params)
+            h = jnp.where(started_m[:, None], h_new, carry.h) if spec.uses_vaoi else carry.h
 
         # aggregation (DELIVERED uploads of this epoch; old-pending uploads
         # use old msgs — a lossy channel shrinks the mask, never the msgs)
-        contrib = jax.tree.map(
-            lambda old, new: jnp.where(
-                pending_in.reshape((-1,) + (1,) * (old.ndim - 1)), old, new
-            ),
-            carry.msg_params,
-            msg_params,
-        )
-        new_global = ops.masked_mean(contrib, upload_mask, carry.global_params)
+        with jax.named_scope("ehfl.fedavg"):
+            contrib = jax.tree.map(
+                lambda old, new: jnp.where(
+                    pending_in.reshape((-1,) + (1,) * (old.ndim - 1)), old, new
+                ),
+                carry.msg_params,
+                msg_params,
+            )
+            new_global = ops.masked_mean(contrib, upload_mask, carry.global_params)
     else:
         # --- active-set compaction (DESIGN.md §11): gather the started
         # clients into a static (cap_loc, ...) slab, train only the slab,
@@ -515,34 +543,36 @@ def epoch_body(
         # they are a subset of the selection mask, whose popcount
         # ``PolicySpec.max_active`` bounds (asserted in tests/test_compact).
         cap_loc = min(cap, n_loc)
-        # stable argsort of the ~started mask: started clients first, in
-        # ascending client order — so slab lane j is the j-th started client
-        slab_idx = jnp.argsort(~st.started)[:cap_loc]
-        slab_valid = jnp.arange(cap_loc) < jnp.sum(st.started.astype(jnp.int32))
-        trained, h_slab = jax.vmap(train_one)(
-            images[slab_idx], labels[slab_idx], train_keys[slab_idx]
-        )
-        # invalid (padding) lanes scatter out of bounds -> dropped
-        scat_idx = jnp.where(slab_valid, slab_idx, n_loc)
-        msg_params = jax.tree.map(
-            lambda mp, tr: mp.at[scat_idx].set(tr, mode="drop"), carry.msg_params, trained
-        )
-        h = (
-            carry.h.at[scat_idx].set(h_slab, mode="drop")
-            if spec.uses_vaoi
-            else carry.h
-        )
+        with jax.named_scope("ehfl.local_train"):
+            # stable argsort of the ~started mask: started clients first, in
+            # ascending client order — so slab lane j is the j-th started client
+            slab_idx = jnp.argsort(~st.started)[:cap_loc]
+            slab_valid = jnp.arange(cap_loc) < jnp.sum(st.started.astype(jnp.int32))
+            trained, h_slab = jax.vmap(train_one)(
+                images[slab_idx], labels[slab_idx], train_keys[slab_idx]
+            )
+            # invalid (padding) lanes scatter out of bounds -> dropped
+            scat_idx = jnp.where(slab_valid, slab_idx, n_loc)
+            msg_params = jax.tree.map(
+                lambda mp, tr: mp.at[scat_idx].set(tr, mode="drop"), carry.msg_params, trained
+            )
+            h = (
+                carry.h.at[scat_idx].set(h_slab, mode="drop")
+                if spec.uses_vaoi
+                else carry.h
+            )
 
         # aggregation: fresh DELIVERED uploads (delivered & ~pending_in, a
         # subset of started) reduce over the slab; pending_in carriers upload
         # their OLD message from the N-wide msg tree (bandwidth-only pass).
         # The channel's delivery mask gates both passes identically to the
         # dense path, so lossy compact == lossy dense stays exact.
-        slab_new = (upload_mask & ~pending_in)[slab_idx] & slab_valid
-        old_mask = upload_mask & pending_in
-        new_global = ops.compact_mean(
-            trained, slab_new, carry.msg_params, old_mask, carry.global_params
-        )
+        with jax.named_scope("ehfl.fedavg"):
+            slab_new = (upload_mask & ~pending_in)[slab_idx] & slab_valid
+            old_mask = upload_mask & pending_in
+            new_global = ops.compact_mean(
+                trained, slab_new, carry.msg_params, old_mask, carry.global_params
+            )
 
     zero = jnp.zeros((), jnp.int32)
     metrics = {
@@ -633,10 +663,12 @@ def drive_epochs(
     t = 0
     while t < cfg.epochs:
         n = min(chunk, cfg.epochs - t)
-        carry, ms = scan_chunk(carry, jnp.arange(t, t + n))
+        with jax.profiler.TraceAnnotation("ehfl.chunk"):
+            carry, ms = scan_chunk(carry, jnp.arange(t, t + n))
         all_metrics.append(ms)
-        preds = eval_fn(carry.global_params, data["test_images"])
-        f1s.append(float(macro_f1(preds, data["test_labels"], backend.num_classes)))
+        with jax.profiler.TraceAnnotation("ehfl.eval"):
+            preds = eval_fn(carry.global_params, data["test_images"])
+            f1s.append(float(macro_f1(preds, data["test_labels"], backend.num_classes)))
         f1_epochs.append(t + n)
         t += n
 
@@ -647,6 +679,27 @@ def drive_epochs(
     return {"metrics": metrics, "global_params": carry.global_params, "carry": carry}
 
 
+def chunk_program(cfg: EHFLConfig, backend: Backend, use_kernel: bool = False) -> Callable:
+    """The jitted epoch program of :func:`run_simulation` (executable
+    ``jit_chunk``): ``(carry, ts, images, labels) -> (carry, metrics)``
+    scans :func:`epoch_body` over the epochs ``ts``.
+
+    The client data enters as jit ARGUMENTS: a closed-over array is baked
+    into the program as a constant, which at paper width (~370 MB of
+    client images) swamps compilation.  The carry is donated: msg_params
+    is N stacked model copies, and without donation every eval_every
+    chunk allocates a fresh copy."""
+
+    def chunk(c, ts, images, labels):
+        with tracing_chunk():
+            epoch_fn = make_epoch_fn(
+                cfg, backend, {"images": images, "labels": labels}, use_kernel=use_kernel
+            )
+            return jax.lax.scan(epoch_fn, c, ts)
+
+    return jax.jit(chunk, donate_argnums=(0,))
+
+
 def run_simulation(
     cfg: EHFLConfig,
     backend: Backend,
@@ -654,22 +707,12 @@ def run_simulation(
     use_kernel: bool = False,
 ) -> Dict[str, Any]:
     """Run T epochs of Alg. 1. Returns metric trajectories + final model."""
-
-    # the client data enters as jit ARGUMENTS: a closed-over array is baked
-    # into the program as a constant, which at paper width (~370 MB of
-    # client images) swamps compilation.  The carry is donated:
-    # msg_params is N stacked model copies, and without donation every
-    # eval_every chunk allocates a fresh copy.
-    def chunk(c, ts, images, labels):
-        epoch_fn = make_epoch_fn(
-            cfg, backend, {"images": images, "labels": labels}, use_kernel=use_kernel
-        )
-        return jax.lax.scan(epoch_fn, c, ts)
-
-    scan_chunk = jax.jit(chunk, donate_argnums=(0,))
+    scan_chunk = chunk_program(cfg, backend, use_kernel)
+    with jax.profiler.TraceAnnotation("ehfl.init_carry"):
+        carry = init_carry(cfg, backend)
     return drive_epochs(
         lambda c, ts: scan_chunk(c, ts, data["images"], data["labels"]),
-        init_carry(cfg, backend), cfg, backend, data,
+        carry, cfg, backend, data,
     )
 
 
