@@ -55,7 +55,7 @@ def bench_one(
         compact="auto" if compact else False,
     )
     t0 = time.time()
-    out = run_simulation(cfg, backend, data)
+    out = jax.block_until_ready(run_simulation(cfg, backend, data))
     wall = time.time() - t0
     m = out["metrics"]
     uploaded = int(np.asarray(m["n_uploaded"]).sum())

@@ -65,7 +65,7 @@ def bench_one(
         compact="auto" if compact else False,
     )
     t0 = time.time()
-    out = run_simulation(cfg, backend, data)
+    out = jax.block_until_ready(run_simulation(cfg, backend, data))
     wall = time.time() - t0
     m = out["metrics"]
     return {

@@ -28,6 +28,10 @@ Four chips (``--chips 4``, this phase only):
   * ``run_fleet`` over a 4-device client mesh, compared with
     ``run_simulation`` on one of those chips (the sharded==solo contract of
     ``tests/test_fleet.py``).
+The jnp ``run_simulation`` runs and the fleet run also have their whole F1
+trajectory held to the eager ``macro_f1`` of each chunk's global model: the
+drivers' loop does not wait between chunks, so chunk k's eval reads the
+global model that chunk k+1 then takes over by donation.
 
 A comparison holds integer dynamics (energy, starts, uploads, batteries,
 pending flags) and VAoI ages exactly equal, and the float results within
@@ -53,6 +57,9 @@ EPOCHS, EVAL_EVERY = 7, 4
 PARAMS_ATOL = 1e-2
 AVG_M_ATOL = 1e-3
 F1_ATOL = 0.1
+# the same predictions' macro-F1, jitted against eager: the same integer
+# counts and float32 divisions
+F1_TRAJECTORY_ATOL = 1e-6
 EXACT_METRICS = (
     "energy", "n_started", "n_uploaded", "n_delivered", "n_failed", "n_dropped",
     "avg_age", "f1_epochs",
@@ -167,6 +174,42 @@ def compare(name: str, ref: dict, got: dict) -> None:
     _require(not bad, f"{name}: {bad}")
 
 
+def check_f1_trajectory(name: str, out: dict, cfg, backend, data, carry, scan_chunk) -> None:
+    """Hold ``out["metrics"]["f1"]`` to the eager ``macro_f1`` of each
+    chunk's global model.  ``carry`` and ``scan_chunk`` are the program that
+    made ``out``: it is driven again, waiting for the device after every
+    chunk and copying the global model before the next chunk's donation."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.simulator import _jitted_predict, drive_epochs
+    from repro.models.cnn import macro_f1
+
+    params = []
+
+    def synced(c, ts):
+        c, ms = jax.block_until_ready(scan_chunk(c, ts))
+        params.append(jax.block_until_ready(jax.tree.map(jnp.copy, c.global_params)))
+        return c, ms
+
+    drive_epochs(synced, carry, cfg, backend, data)
+    predict = _jitted_predict(backend.predict)
+    want = np.array([
+        float(macro_f1(predict(p, data["test_images"]), data["test_labels"], backend.num_classes))
+        for p in params
+    ])
+    got = np.asarray(out["metrics"]["f1"])
+    diff = _max_abs_diff(got, want) if got.shape == want.shape else float("inf")
+    ok = got.dtype == np.float32 and diff <= F1_TRAJECTORY_ATOL
+    print(json.dumps({
+        "compare": f"{name} f1 vs eager macro_f1 per chunk", "ok": ok, "f1": got.tolist(),
+        "eager_f1": want.tolist(), "max_f1_abs_diff": diff, "atol": F1_TRAJECTORY_ATOL,
+        "max_param_abs_diff_last_chunk": _max_param_diff(out, {"global_params": params[-1]}),
+    }), flush=True)
+    _require(ok, f"{name}: f1 {got.tolist()} ({got.dtype}) vs eager {want.tolist()}")
+
+
 def _max_abs_diff(a, b) -> float:
     import numpy as np
 
@@ -188,7 +231,7 @@ def one_chip(cfg, backend, data) -> None:
     import numpy as np
 
     from repro.core import run_batch, run_simulation
-    from repro.core.simulator import resolve_compact_cap
+    from repro.core.simulator import chunk_program, init_carry, resolve_compact_cap
     from repro.core.policies import make_policy
 
     fedavg = dataclasses.replace(cfg, policy="fedavg")
@@ -204,6 +247,9 @@ def one_chip(cfg, backend, data) -> None:
         if c.policy == "vaoi":
             _require((np.asarray(ref["metrics"]["n_started"]) <= c.k).all(), tag)
         runs[tag] = ref
+        scan = chunk_program(c, backend)
+        check_f1_trajectory(tag, ref, c, backend, data, init_carry(c, backend),
+                            lambda c_, ts: scan(c_, ts, data["images"], data["labels"]))
     for c, tag in ((cfg, "vaoi_compact"), (fedavg, "fedavg_dense")):
         ker = run_phase(
             f"{tag}_kernel", lambda: run_simulation(c, backend, data, use_kernel=True),
@@ -230,6 +276,7 @@ def four_chips(cfg, backend, data) -> None:
     import jax
 
     from repro.core import run_fleet, run_simulation
+    from repro.core.fleet import fleet_program
     from repro.launch.mesh import make_fleet_mesh
 
     mesh = make_fleet_mesh(4, num_clients=cfg.num_clients)
@@ -240,6 +287,9 @@ def four_chips(cfg, backend, data) -> None:
     for leaf in jax.tree.leaves(fleet["carry"].msg_params):
         devices = {s.device for s in leaf.addressable_shards}
         _require(len(devices) == 4, f"msg_params leaf {leaf.shape} lives on {devices}")
+    carry, scan, sharded, _ = fleet_program(cfg, backend, data, mesh=mesh)
+    check_f1_trajectory("fleet", fleet, cfg, backend, data, carry,
+                        lambda c, ts: scan(c, ts, sharded["images"], sharded["labels"]))
     solo = run_phase("solo_1_chip", lambda: run_simulation(cfg, backend, data))
     compare("fleet vs solo", solo, fleet)
 

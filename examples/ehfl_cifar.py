@@ -114,14 +114,14 @@ def main() -> None:
     if args.fleet:
         from repro.core.fleet import run_fleet
 
-        out = run_fleet(cfg, backend, data)
+        out = jax.block_until_ready(run_fleet(cfg, backend, data))
         wall = time.time() - t0
         m = out["metrics"]
         params = out["global_params"]
         print(f"fleet: N={args.clients} sharded over {out['num_shards']} device(s)")
     elif args.num_seeds > 1:
         seeds = [args.seed + i for i in range(args.num_seeds)]
-        out = run_batch(cfg, backend, data, seeds)
+        out = jax.block_until_ready(run_batch(cfg, backend, data, seeds))
         wall = time.time() - t0
         # report seed means (every metric has a leading seed axis except the
         # shared eval schedule); keep seed 0's model for the checkpoint
@@ -129,7 +129,7 @@ def main() -> None:
              for k, v in out["metrics"].items()}
         params = jax.tree.map(lambda x: x[0], out["global_params"])
     else:
-        out = run_simulation(cfg, backend, data)
+        out = jax.block_until_ready(run_simulation(cfg, backend, data))
         wall = time.time() - t0
         m = out["metrics"]
         params = out["global_params"]

@@ -641,6 +641,16 @@ def _jitted_predict(predict: Callable) -> Callable:
     return jax.jit(predict)
 
 
+@functools.lru_cache(maxsize=1)
+def _jitted_f1() -> Callable:
+    """``macro_f1`` as one jitted program (executable ``jit_macro_f1``,
+    ``num_classes`` static): run eagerly, its per-class loop dispatches
+    about 180 tiny programs per eval."""
+    from repro.models.cnn import macro_f1
+
+    return jax.jit(macro_f1, static_argnames="num_classes")
+
+
 def drive_epochs(
     scan_chunk: Callable,
     carry: EpochCarry,
@@ -652,12 +662,19 @@ def drive_epochs(
     scan epochs in ``eval_every`` chunks with periodic macro-F1 eval.
     ``scan_chunk(carry, ts) -> (carry, metrics)`` hides solo vs sharded.
 
+    The loop only dispatches: each chunk's eval (predict, then the jitted
+    F1, inside the ``ehfl.eval`` span) is enqueued behind its chunk, and
+    the F1s stay on the device until the caller reads ``metrics["f1"]``,
+    so the host never waits for the device between chunks.  The returned
+    arrays therefore come back before the device work is done: a caller
+    that times the call blocks on them first (``jax.block_until_ready``).
+
     ``scan_chunk`` may donate its carry argument (both callers do): the
     loop never reuses a carry after passing it in."""
     all_metrics = []
     f1s, f1_epochs = [], []
     eval_fn = _jitted_predict(backend.predict)
-    from repro.models.cnn import macro_f1
+    f1_fn = _jitted_f1()
 
     chunk = max(1, cfg.eval_every)
     t = 0
@@ -668,12 +685,12 @@ def drive_epochs(
         all_metrics.append(ms)
         with jax.profiler.TraceAnnotation("ehfl.eval"):
             preds = eval_fn(carry.global_params, data["test_images"])
-            f1s.append(float(macro_f1(preds, data["test_labels"], backend.num_classes)))
+            f1s.append(f1_fn(preds, data["test_labels"], num_classes=backend.num_classes))
         f1_epochs.append(t + n)
         t += n
 
     metrics = {k: jnp.concatenate([m[k] for m in all_metrics]) for k in all_metrics[0]}
-    metrics["f1"] = jnp.array(f1s)
+    metrics["f1"] = jnp.stack(f1s)
     metrics["f1_epochs"] = jnp.array(f1_epochs)
     metrics["total_energy"] = jnp.sum(metrics["energy"])
     return {"metrics": metrics, "global_params": carry.global_params, "carry": carry}

@@ -1,18 +1,26 @@
 """The host spans, retrace counter and the epoch body's named scopes
 (README "Tracing"): what a profiler trace or a monitoring listener sees of a
-tiny run, and which scopes each epoch program carries in its op names."""
+tiny run, and which scopes each epoch program carries in its op names.
+Between those spans, ``drive_epochs`` only dispatches: no device value
+reaches the host while its chunk loop runs, and the F1 trajectory it keeps
+on the device is the eager macro-F1 of each chunk's global model."""
 import collections
+import contextlib
+import inspect
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax._src import array as jax_array
 
 from repro.configs.cifar_cnn import CNNConfig
-from repro.core import EHFLConfig, fleet, run_simulation
+from repro.core import EHFLConfig, fleet, run_fleet, run_simulation
 from repro.core import simulator
 from repro.data import make_federated_dataset
 from repro.fl import cnn_backend
+from repro.models.cnn import macro_f1
 
 TINY_CNN = CNNConfig(name="tiny", image_size=8, conv_channels=(4, 4, 8, 8, 8, 8), fc_dims=(16, 8))
 N = 8
@@ -111,3 +119,74 @@ def test_profiler_trace_holds_the_host_spans(tmp_path, backend, world):
     assert first_chunk[0] <= ts and tt <= first_chunk[1]
     assert by[spans.INIT_SPAN][0][1] <= first_chunk[0]
     assert first_chunk[1] <= by[spans.EVAL_SPAN][0][0] <= by[spans.EVAL_SPAN][0][1] <= second_chunk[0]
+
+
+@pytest.fixture
+def events(monkeypatch):
+    """In order: ``("enter" | "exit", span)`` for every host span, and
+    ``("read", shape)`` for every host readback of a jax array (``float()``,
+    ``int()``, ``np.asarray``, ``.item()`` and ``.tolist()`` all read
+    ``ArrayImpl._value``)."""
+    log = []
+    # ``ArrayImpl._value`` is private: this patch point was checked against
+    # JAX 0.9.0.  Should a later JAX read arrays back another way, the last
+    # assertion of the test using this fixture fails rather than pass blind.
+    value = inspect.getattr_static(jax_array.ArrayImpl, "_value")
+
+    def read(self):
+        log.append(("read", self.shape))
+        return value.fget(self)
+
+    annotation = jax.profiler.TraceAnnotation
+
+    @contextlib.contextmanager
+    def span(name, **kw):
+        log.append(("enter", name))
+        try:
+            with annotation(name, **kw):
+                yield
+        finally:
+            log.append(("exit", name))
+
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(read))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", span)
+    return log
+
+
+@pytest.mark.parametrize("driver", [run_simulation, run_fleet])
+def test_no_readback_inside_the_chunk_loop(driver, events, backend, world):
+    """From the first chunk's dispatch to the last eval's, the loop hands
+    the device work and takes nothing back."""
+    cfg = _cfg(seed=2)
+    out = driver(cfg, backend, world)
+    first = events.index(("enter", "ehfl.chunk"))
+    last = len(events) - 1 - events[::-1].index(("exit", "ehfl.eval"))
+    assert events.count(("enter", "ehfl.eval")) == cfg.epochs // cfg.eval_every
+    assert [e for e in events[first:last + 1] if e[0] == "read"] == []
+    float(out["metrics"]["f1"][-1])
+    assert events[-1] == ("read", ()), "the readback probe does not see float()"
+
+
+def test_f1_trajectory_is_the_eager_f1_of_each_chunk(backend, world):
+    """``metrics["f1"]`` holds, per chunk, the eager ``macro_f1`` of the
+    predictions for that chunk's global model: float32, one per eval, the
+    short last chunk included."""
+    cfg = _cfg(seed=3, epochs=6)
+    scan = simulator.chunk_program(cfg, backend)
+    params = []
+
+    def scan_chunk(c, ts):
+        c, ms = scan(c, ts, world["images"], world["labels"])
+        params.append(jax.tree.map(jnp.copy, c.global_params))
+        return c, ms
+
+    out = simulator.drive_epochs(
+        scan_chunk, simulator.init_carry(cfg, backend), cfg, backend, world)
+    f1 = out["metrics"]["f1"]
+    assert f1.dtype == jnp.float32 and f1.shape == (2,)
+    np.testing.assert_array_equal(np.asarray(out["metrics"]["f1_epochs"]), [4, 6])
+    predict = simulator._jitted_predict(backend.predict)
+    want = [float(macro_f1(predict(p, world["test_images"]), world["test_labels"],
+                           backend.num_classes)) for p in params]
+    np.testing.assert_allclose(np.asarray(f1), want, rtol=0, atol=1e-7)
+    assert max(want) > 0, "an F1 of zero throughout would not test the eval"
