@@ -8,8 +8,9 @@ Runs on the chip, in one process, at the cell's own sizes:
 
 * ``program``: one call of the cell's entry point per seed, checked by
   ``bench/compare.py`` exactly as a benchmark run checks its last call;
-* ``control``: the reference in bfloat16 at default precision
-  (``reference.CONTROL``) put in the program's place, checked the same way;
+* ``control``: the reference, its model the family's at
+  ``reference.CONTROL`` numerics (bfloat16 at default precision), put in the
+  program's place and checked the same way;
 * ``fault``: the program with one fault of ``bench/faults.py`` planted, one
   call per seed, checked the same way;
 * ``m_gap``: the program stepped one epoch at a time beside the lockstep
@@ -45,41 +46,50 @@ def main() -> int:
     ap.add_argument("--trace-out", default="")
     args = ap.parse_args()
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+    from bench import harness
 
+    cell = harness.load_cell(ROOT, args.workload)
+    harness.check_devices(cell.workload["chips"])
+    return calibrate(cell, args)
+
+
+def calibrate(cell, args) -> int:
+    """The readings ``args`` ask for, of ``cell``, in this process."""
     import jax
     import numpy as np
 
     from bench import compare, faults, harness, reference
-    from bench.data import make_dataset
 
-    cell = harness.load_cell(ROOT, args.workload)
-    harness.check_devices(cell.workload["chips"])
-    harness.enable_cache(ROOT)
-    sim, model, dcfg = harness.sim_settings(cell), cell.config["model"], cell.config["data"]
+    harness.enable_cache(cell.root)
+    sim, model, fam = harness.sim_settings(cell), cell.config["model"], cell.family
     N, H, policy = sim["num_clients"], cell.traffic["horizon"], cell.traffic["policy"]
     margin = cell.limits["margin"]
-    be, call = harness.backend(cell), harness.entry(cell)
+    call = harness.entry(cell)
 
     def emit(kind, seed, **kw):
         print(json.dumps({"kind": kind, "seed": seed, **kw}), flush=True)
 
-    def check(kind, seed, got, data, t0):
-        ref = reference.simulate(sim, model, data, seed, H, policy,
+    def inputs(seed):
+        frozen = harness.make_frozen(cell, seed)
+        return frozen, fam.make_dataset(seed, cell.config["data"], model)
+
+    def check(kind, seed, got, frozen, data, t0):
+        ref = reference.simulate(sim, fam, model, data, seed, H, policy, frozen=frozen,
                                  witness=compare.age_sums(got["metrics"]["avg_age"], N),
                                  margin=margin)
-        f1 = reference.eval_f1(model, got["global_params"], data)
-        nums = compare.numbers(got, ref, f1, N)
+        f1 = reference.eval_f1(fam, model, got["global_params"], data, frozen)
+        nums = compare.numbers(got, ref, f1, N, fam, data)
         emit(kind, seed, **nums, min_margin=ref["min_margin"], seconds=time.perf_counter() - t0)
 
     def program(kind, seeds):
-        be = harness.backend(cell)  # after any fault's patch
         for seed in seeds:
             t0 = time.perf_counter()
-            data = make_dataset(seed, dcfg, model)
+            frozen, data = inputs(seed)
+            be = harness.backend(cell, frozen)  # after any fault's patch
             out = call(harness.sim_config(cell, seed, H), be, data)
-            got = jax.tree.map(np.asarray, harness._keep(out))
+            got = jax.tree.map(np.asarray, harness._keep(out, fam.to_reference))
             del out
-            check(kind, seed, got, data, t0)
+            check(kind, seed, got, frozen, data, t0)
 
     program("program", _seeds(args.program_seeds))
     for name in [f for f in args.faults.split(",") if f]:
@@ -90,21 +100,18 @@ def main() -> int:
 
     for seed in _seeds(args.control_seeds):
         t0 = time.perf_counter()
-        data = make_dataset(seed, dcfg, model)
-        c = reference.simulate(sim, model, data, seed, H, policy, numerics=reference.CONTROL)
-        got = {
-            "metrics": {**c["metrics"], "n_delivered": c["metrics"]["n_uploaded"], "f1": c["f1"]},
-            "global_params": c["global_params"], "msg_params": c["msg_params"],
-            "final": c["final"],
-        }
-        check("control", seed, got, data, t0)
+        frozen, data = inputs(seed)
+        c = reference.simulate(sim, fam, model, data, seed, H, policy, frozen=frozen,
+                               numerics=reference.CONTROL)
+        check("control", seed, as_program(c), frozen, data, t0)
 
     if args.m_seeds:
         from repro.core import simulator, vaoi
 
         for seed in _seeds(args.m_seeds):
             t0 = time.perf_counter()
-            data = make_dataset(seed, dcfg, model)
+            frozen, data = inputs(seed)
+            be = harness.backend(cell, frozen)
             cfg = harness.sim_config(cell, seed, H)
             step = jax.jit(simulator.make_epoch_fn(cfg, be, data))
             probe = data["images"][:, : sim["probe_size"]]
@@ -116,18 +123,28 @@ def main() -> int:
                 carry, met = step(carry, np.int32(t))
                 sums.append(int(np.rint(float(met["avg_age"]) * N)))
             log = []
-            ref = reference.simulate(sim, model, data, seed, H, policy, witness=sums,
-                                     margin=margin, m_log=log)
+            ref = reference.simulate(sim, fam, model, data, seed, H, policy, frozen=frozen,
+                                     witness=sums, margin=margin, m_log=log)
             gaps = [float(np.max(np.abs(ms[t] - log[t]))) for t in range(len(log))]
             emit("m_gap", seed, t_stop=ref["t_stop"], max_gap=max(gaps) if gaps else None,
                  per_epoch=gaps, min_margin=ref["min_margin"], seconds=time.perf_counter() - t0)
 
     if args.trace_out:
-        record_trace(cell, be, call, Path(args.trace_out))
+        record_trace(cell, call, Path(args.trace_out))
     return 0
 
 
-def record_trace(cell, be, call, dest: Path) -> None:
+def as_program(c: dict) -> dict:
+    """A reference run's output in the form the check reads of a program
+    call (``harness._keep``)."""
+    return {
+        "metrics": {**c["metrics"], "n_delivered": c["metrics"]["n_uploaded"], "f1": c["f1"]},
+        "global_params": c["global_params"], "msg_params": c["msg_params"],
+        "final": c["final"],
+    }
+
+
+def record_trace(cell, call, dest: Path) -> None:
     """Two short calls at 10 clients, traced with the benchmark's spans."""
     import dataclasses
 
@@ -135,7 +152,6 @@ def record_trace(cell, be, call, dest: Path) -> None:
 
     from bench import harness
     from bench import trace as tr
-    from bench.data import make_dataset
 
     small = dataclasses.replace(cell, config={
         **cell.config,
@@ -143,10 +159,11 @@ def record_trace(cell, be, call, dest: Path) -> None:
                  "test_size": 50},
         "sim": {**cell.config["sim"], "num_clients": 10, "k": 2, "p_bc": 0.5},
     })
-    data = make_dataset(0, small.config["data"], small.config["model"])
+    data = cell.family.make_dataset(0, small.config["data"], small.config["model"])
+    be = harness.backend(small, harness.make_frozen(small, 0))
     cfg = harness.sim_config(small, 0, small.config["sim"]["eval_every"])
     jax.block_until_ready(call(cfg, be, data))
-    tmp = ROOT / "bench" / ".trace" / "fixture"
+    tmp = cell.bench_dir / ".trace" / "fixture"
     shutil.rmtree(tmp, ignore_errors=True)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

@@ -41,15 +41,24 @@ Numbers compared (each is held to a limit of its cell's
   of the others grow without bound.
 * ``f1_gap`` -- the program's last macro-F1 against the reference's forward
   on the program's final global model: the eval layer.
+
+A model family may add numbers of its own (its ``NUMBERS``, from its
+``numbers(prog, ref, data)``; ``bench/models/__init__.py``).  The program's
+messages reach this module as the family's ``to_reference`` gives them: the
+flat leaves of the reference's model.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Tuple
+from types import ModuleType
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 INT_METRICS = ("n_started", "n_uploaded", "energy")
+# the numbers a cell's limits may hold, besides its family's ``NUMBERS``
+NUMBERS = ("int_mismatch", "probe_m_gap", "age_level_gap", "fedavg_gap", "param_change_gap",
+           "f1_gap")
 
 
 def age_sums(avg_age: np.ndarray, num_clients: int) -> np.ndarray:
@@ -59,9 +68,11 @@ def age_sums(avg_age: np.ndarray, num_clients: int) -> np.ndarray:
 
 
 def numbers(prog: Dict[str, Any], ref: Dict[str, Any], f1_recomputed: float,
-            num_clients: int) -> Dict[str, float]:
-    """Every number the comparison reads of one call; which of them are held
-    to a limit is the cell's choice (``bench/limits``)."""
+            num_clients: int, family: Optional[ModuleType] = None,
+            data: Optional[Dict[str, Any]] = None) -> Dict[str, float]:
+    """Every number the comparison reads of one call, with ``family``'s own;
+    which of them are held to a limit is the cell's choice
+    (``bench/limits``)."""
     pm, rm = prog["metrics"], ref["metrics"]
     t_stop, epochs = ref["t_stop"], len(np.asarray(pm["energy"]))
     mism = int(ref["witness_faults"])
@@ -99,6 +110,8 @@ def numbers(prog: Dict[str, Any], ref: Dict[str, Any], f1_recomputed: float,
             prog["global_params"],
             {k: np.mean(np.asarray(msgs[k], np.float64)[ups], axis=0) for k in init},
             init) if ups.size else 0.0
+    if family is not None and hasattr(family, "numbers"):
+        out.update(family.numbers(prog, ref, data))
     return out
 
 

@@ -47,8 +47,8 @@ def half_the_batch(patch: Callable) -> None:
 
     orig = harness.backend
 
-    def backend(cell):
-        be = orig(cell)
+    def backend(cell, frozen=None):
+        be = orig(cell, frozen)
         grad = be.grad_loss
         return be._replace(grad_loss=lambda p, x, y: grad(p, x[: x.shape[0] // 2],
                                                          y[: y.shape[0] // 2]))
@@ -94,13 +94,13 @@ def probe_neighbour_images(patch: Callable) -> None:
 
 
 def altered_prediction(patch: Callable) -> None:
-    """The eval's predictions are altered where they are produced."""
+    """The eval's predictions are altered where they are produced: each is
+    the next class (the last one none)."""
     from repro.core import simulator
 
     orig = simulator._jitted_predict
     simulator._jitted_predict.cache_clear()
-    patch(simulator, "_jitted_predict",
-          lambda predict: (lambda p, x: (orig(predict)(p, x) + 1) % 10))
+    patch(simulator, "_jitted_predict", lambda predict: (lambda p, x: orig(predict)(p, x) + 1))
 
 
 FAULTS = {f.__name__: f for f in (unchanged_training, half_the_uploads, half_the_batch,

@@ -5,12 +5,14 @@ Everything a cell needs is found by name (nothing here names a cell):
 
 * ``BENCHMARK.json``              -- the cell: its configuration, traffic, chips;
 * ``<config file>``               -- sizes of model, data and simulator;
+* ``bench/models/<family>.py``    -- the model family the configuration names:
+  the program's backend, the reference model, the data, operation counts;
 * ``bench/traffic/<traffic>.json`` -- entry point, policy, horizon per call;
 * ``bench/limits/<workload>.json`` -- the limits of the numbers compared;
 * ``bench/metrics/<metric>.py``   -- one reader per per-layer metric.
 
-A run: make the data on the device from ``--seed``; warm up with one call
-(it loads or compiles exactly the window's programs);
+A run: make the data (and any frozen weights) on the device from ``--seed``;
+warm up with one call (it loads or compiles exactly the window's programs);
 then call the entry point with seeds ``seed, seed+1, ...``, each call a fresh
 ``H``-epoch simulation, until ``--seconds`` have passed.  The last call, the
 one that ends the window, is then checked against the reference.
@@ -18,17 +20,18 @@ one that ends the window, is then checked against the reference.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import os
 import shutil
 import sys
 import time
 from pathlib import Path
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 from typing import Any, Callable, Dict, List
 
 import numpy as np
+
+from bench import models
 
 _COMPILE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -54,6 +57,7 @@ class Cell:
     config: dict
     traffic: dict
     limits: dict
+    family: ModuleType  # bench/models/<config's model.family>.py
 
     @property
     def bench_dir(self) -> Path:
@@ -75,23 +79,22 @@ def load_cell(root: Path, workload: str) -> Cell:
     w = by_name[workload]
     cfg = {c["name"]: c for c in spec["configs"]}[w["config"]]
     bench = root / spec["paths"][0]
+    config = json.loads((root / cfg["file"]).read_text())
     return Cell(
         root=root,
         spec=spec,
         workload=w,
-        config=json.loads((root / cfg["file"]).read_text()),
+        config=config,
         traffic=json.loads((bench / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=json.loads((bench / "limits" / f"{workload}.json").read_text()),
+        family=models.load(bench, config["model"]),
     )
 
 
 def load_reader(cell: Cell, metric: str) -> Callable:
     """``bench/metrics/<metric>.py``'s ``read(ctx)``."""
     path = cell.bench_dir / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return models.load_module(path, f"bench_metric_{metric}").read
 
 
 # --------------------------------------------------------------------------
@@ -133,15 +136,14 @@ def sim_config(cell: Cell, seed: int, epochs: int):
     )
 
 
-def backend(cell: Cell):
-    from repro.configs.cifar_cnn import CNNConfig
-    from repro.fl import cnn_backend
+def make_frozen(cell: Cell, seed: int) -> Any:
+    """The family's frozen weights from ``seed``, or ``None``."""
+    make = getattr(cell.family, "make_frozen", None)
+    return make(seed, cell.config["model"]) if make else None
 
-    m = cell.config["model"]
-    return cnn_backend(CNNConfig(
-        image_size=m["image_size"], in_channels=m["in_channels"], num_classes=m["num_classes"],
-        conv_channels=tuple(m["conv_channels"]), fc_dims=tuple(m["fc_dims"]),
-    ))
+
+def backend(cell: Cell, frozen: Any = None):
+    return cell.family.program_backend(cell.config["model"], frozen)
 
 
 def entry(cell: Cell) -> Callable:
@@ -155,12 +157,13 @@ def entry(cell: Cell) -> Callable:
     return lambda cfg, be, data: core.run_simulation(cfg, be, data, use_kernel=use_kernel)
 
 
-def _keep(out: Dict[str, Any]) -> Dict[str, Any]:
+def _keep(out: Dict[str, Any], to_reference: Callable) -> Dict[str, Any]:
     """What the check reads of one call: its metrics, the global model and
-    the final per-client state with every client's last message."""
+    the final per-client state with every client's last message, the models
+    as the reference's leaves (the family's ``to_reference``)."""
     c = out["carry"]
-    return {"metrics": out["metrics"], "global_params": out["global_params"],
-            "msg_params": c.msg_params,
+    return {"metrics": out["metrics"], "global_params": to_reference(out["global_params"]),
+            "msg_params": to_reference(c.msg_params),
             "final": {"age": c.age, "battery": c.battery, "pending": c.pending}}
 
 
@@ -215,7 +218,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
 
     from bench import compare, reference
     from bench import trace as tr
-    from bench.data import make_dataset
 
     chips = cell.workload["chips"]
     devices = check_devices(chips) if require_chip else jax.devices()
@@ -225,11 +227,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     jax.monitoring.register_event_listener(events.count)
 
     H = cell.traffic["horizon"]
-    sim, model, dcfg = sim_settings(cell), cell.config["model"], cell.config["data"]
-    be = backend(cell)
+    sim, model, fam = sim_settings(cell), cell.config["model"], cell.family
+    frozen = make_frozen(cell, seed)
+    be = backend(cell, frozen)
     call = entry(cell)
-    data = make_dataset(seed, dcfg, model)
-    jax.block_until_ready(data)
+    data = fam.make_dataset(seed, cell.config["data"], model)
+    jax.block_until_ready((frozen, data))
     data_s = time.perf_counter() - t_start
 
     # the warm-up is a whole call: besides the chunk and eval programs,
@@ -274,7 +277,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     n_calls = len(started)
     n_started = int(sum(int(np.asarray(s).sum()) for s in started))
     pick = n_calls - 1
-    checked = jax.tree.map(np.asarray, _keep(out))
+    checked = jax.tree.map(np.asarray, _keep(out, fam.to_reference))
     del out
 
     compile_s = sum(events.durations.get(e, 0.0) for e in _COMPILE_EVENTS)
@@ -292,10 +295,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     N = sim["num_clients"]
     policy = cell.traffic["policy"]
     witness = compare.age_sums(checked["metrics"]["avg_age"], N)
-    ref = reference.simulate(sim, model, data, seed + pick, H, policy,
+    ref = reference.simulate(sim, fam, model, data, seed + pick, H, policy, frozen=frozen,
                              witness=witness, margin=cell.limits["margin"])
-    f1_ref = reference.eval_f1(model, checked["global_params"], data)
-    nums = compare.numbers(checked, ref, f1_ref, N)
+    f1_ref = reference.eval_f1(fam, model, checked["global_params"], data, frozen)
+    nums = compare.numbers(checked, ref, f1_ref, N, fam, data)
     correct, rows = compare.judge(nums, cell.limits["limits"])
     print(json.dumps({"check_s": time.perf_counter() - t0 - window_s}), flush=True)
 

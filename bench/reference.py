@@ -2,16 +2,16 @@
 
 Written from the paper's description and the configuration file alone; it
 imports nothing of the program.  Python loops over epochs, slots, clients
-and SGD steps; numpy for the integer slot dynamics; the CNN in plain
-``jax.numpy`` at ``highest`` precision in float32 (the probe forward takes
-every client's probe images through one pass of the model's batch axis).
-No vmap, no scan, no compaction, no kernels.  The random draws follow the same PRNG recipe as the
-simulator (the key split per epoch, Bernoulli arrivals per slot, top-k with
-uniform tie-break noise, one permutation per client), so that a correct
+and SGD steps; numpy for the integer slot dynamics; the client model is the
+configuration's family's plain reference (``bench/models/<family>.py``), at
+``highest`` precision in float32.  No vmap, no scan, no compaction, no
+kernels.  The random draws follow the same PRNG recipe as the simulator (the
+key split per epoch, Bernoulli arrivals per slot, top-k with uniform
+tie-break noise, one permutation per client), so that a correct
 simulator and this reference make the same decisions wherever precision does
 not decide them.
 
-``numerics=CONTROL`` runs the same reference in bfloat16 at default
+``numerics=CONTROL`` runs the same reference model in bfloat16 at default
 precision: the lower precision a later change might be tempted to use.  The
 benchmark's own runs never run it; ``bench/calibrate.py`` and the tests do.
 
@@ -31,6 +31,7 @@ that would leave the lockstep meaningful (PERF.md).  Epochs before
 from __future__ import annotations
 
 import functools
+from types import ModuleType
 from typing import Any, Dict, List, NamedTuple, Sequence
 
 import jax
@@ -45,94 +46,6 @@ class Numerics(NamedTuple):
 
 REFERENCE = Numerics(jnp.float32, jax.lax.Precision.HIGHEST)
 CONTROL = Numerics(jnp.bfloat16, jax.lax.Precision.DEFAULT)
-
-
-# --------------------------------------------------------------------------
-# The client model: 6 conv (3x3, SAME, ReLU; 2x2 max-pool after every second),
-# then fully connected layers, softmax output (paper §V).
-# --------------------------------------------------------------------------
-
-
-def init_params(model: dict, key: jax.Array) -> Dict[str, jax.Array]:
-    convs, fcs = model["conv_channels"], model["fc_dims"]
-    ks = jax.random.split(key, len(convs) + len(fcs) + 1)
-    p, cin = {}, model["in_channels"]
-    for i, cout in enumerate(convs):
-        p[f"conv{i}_w"] = jax.random.normal(ks[i], (3, 3, cin, cout), jnp.float32) * (
-            1.0 / jnp.sqrt(9 * cin)
-        )
-        p[f"conv{i}_b"] = jnp.zeros((cout,), jnp.float32)
-        cin = cout
-    side = model["image_size"] // 8
-    dims = [side * side * convs[-1], *fcs, model["num_classes"]]
-    for i in range(len(dims) - 1):
-        w = jax.random.normal(ks[len(convs) + i], (dims[i], dims[i + 1]), jnp.float32)
-        p[f"fc{i}_w"] = w / jnp.sqrt(dims[i])
-        p[f"fc{i}_b"] = jnp.zeros((dims[i + 1],), jnp.float32)
-    return p
-
-
-def logits(model: dict, num: Numerics, p: dict, x: jax.Array) -> jax.Array:
-    x = x.astype(num.dtype)
-    for i in range(len(model["conv_channels"])):
-        x = jax.lax.conv_general_dilated(
-            x, p[f"conv{i}_w"].astype(num.dtype), (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=num.precision,
-        )
-        x = jnp.maximum(x + p[f"conv{i}_b"].astype(num.dtype), 0)
-        if i % 2 == 1:
-            x = jax.lax.reduce_window(
-                x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID"
-            )
-    x = x.reshape(x.shape[0], -1)
-    n_fc = len(model["fc_dims"]) + 1
-    for i in range(n_fc):
-        x = jnp.dot(x, p[f"fc{i}_w"].astype(num.dtype), precision=num.precision)
-        x = x + p[f"fc{i}_b"].astype(num.dtype)
-        if i < n_fc - 1:
-            x = jnp.maximum(x, 0)
-    return x
-
-
-def _softmax_mean(z: jax.Array) -> jax.Array:
-    z = z - jnp.max(z, axis=-1, keepdims=True)
-    e = jnp.exp(z)
-    return jnp.mean(e / jnp.sum(e, axis=-1, keepdims=True), axis=0)
-
-
-def _loss(model, num, p, x, y):
-    z = logits(model, num, p, x)
-    z = z - jnp.max(z, axis=-1, keepdims=True)
-    logz = jnp.log(jnp.sum(jnp.exp(z), axis=-1))
-    gold = jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - gold)
-
-
-class Model:
-    """Jitted pieces of the reference model."""
-
-    def __init__(self, model: dict, num: Numerics, lr: float):
-        self.predict = jax.jit(lambda p, x: jnp.argmax(logits(model, num, p, x), axis=-1))
-
-        def probe(p, x):
-            # every client's probe batch through one forward pass (the batch
-            # axis of the model), then each client's mean softmax
-            n, b = x.shape[:2]
-            z = logits(model, num, p, x.reshape((n * b,) + x.shape[2:]))
-            z = z - jnp.max(z, axis=-1, keepdims=True)
-            e = jnp.exp(z)
-            return jnp.mean((e / jnp.sum(e, axis=-1, keepdims=True)).reshape(n, b, -1), axis=1)
-
-        self.probe = jax.jit(probe)
-
-        def sgd_step(p, fsum, xs, ys, step):
-            x, y = xs[step], ys[step]
-            g = jax.grad(lambda q: _loss(model, num, q, x, y))(p)
-            p = {k: (p[k] - jnp.asarray(lr, num.dtype) * g[k]).astype(num.dtype) for k in p}
-            f = _softmax_mean(logits(model, num, p, x)).astype(jnp.float32)
-            return p, fsum + f * x.shape[0]
-
-        self.sgd_step = jax.jit(sgd_step)
 
 
 # --------------------------------------------------------------------------
@@ -194,12 +107,14 @@ def _mean_of(msgs: List[dict]) -> dict:
 
 def simulate(
     cfg: dict,
+    family: ModuleType,
     model: dict,
     data: Dict[str, Any],
     seed: int,
     epochs: int,
     policy: str,
     *,
+    frozen: Any = None,
     numerics: Numerics = REFERENCE,
     witness: Sequence[int] | None = None,
     margin: float = 0.0,
@@ -208,7 +123,9 @@ def simulate(
     """Run Alg. 1 for ``epochs`` epochs from ``seed``.
 
     ``cfg`` holds the simulator settings of the configuration file (N, S,
-    kappa, p_bc, k, mu, e_max, probe_size, lr, eval_every); ``policy`` is
+    kappa, p_bc, k, mu, e_max, probe_size, lr, eval_every); ``family`` and
+    ``model`` are the client model's family module and its configuration,
+    ``frozen`` its ``make_frozen`` weights; ``policy`` is
     ``"vaoi"`` (Alg. 2 top-k by VAoI) or ``"fedavg"`` (every client, as soon
     as it has the energy).  ``witness``: the program's per-epoch sum of
     ages, for the lockstep (module docstring).  ``m_log``, if given,
@@ -228,7 +145,8 @@ def simulate(
     k, mu, e_max = cfg["k"], cfg["mu"], cfg["e_max"]
     probe, eval_every = cfg["probe_size"], cfg["eval_every"]
     dt = numerics.dtype
-    net = Model(model, numerics, cfg["lr"])
+    net = family.reference_model(model, numerics, cfg["lr"], frozen)
+    classes = family.num_classes(model)
 
     images, labels = data["images"], data["labels"]
     pool = images.shape[1]
@@ -238,13 +156,13 @@ def simulate(
 
     key = jax.random.PRNGKey(seed)
     k_init, key = jax.random.split(key)
-    init = init_params(model, k_init)
+    init = net.init(k_init)
     glob = {n: v.astype(dt) for n, v in init.items()}
     msgs: List[dict] = [glob] * N
     # who uploaded in the last epoch that had uploads, while none of them
     # has trained since
     last_ups: List[int] = []
-    h = [np.zeros(model["num_classes"], np.float64) for _ in range(N)]
+    h = [np.zeros(net.feature_dim, np.float64) for _ in range(N)]
     age = np.zeros(N, np.int64)
     battery = np.zeros(N, np.int64)
     pending = np.zeros(N, bool)
@@ -325,7 +243,7 @@ def simulate(
         # --- local training (kappa SGD steps over one permutation pass) ---
         new_msgs = list(msgs)
         for i in np.flatnonzero(started):
-            params, fsum = glob, jnp.zeros((model["num_classes"],), jnp.float32)
+            params, fsum = glob, jnp.zeros((net.feature_dim,), jnp.float32)
             perm = jax.random.permutation(train_keys[i], pool)
             idx = perm[: kappa * bs].reshape(kappa, bs)
             xs, ys = images[i][idx], labels[i][idx]
@@ -354,7 +272,7 @@ def simulate(
 
         if (t + 1) % eval_every == 0 or t + 1 == epochs:
             preds = np.asarray(net.predict(glob, test_images))
-            f1s.append(macro_f1(preds, test_labels, model["num_classes"]))
+            f1s.append(macro_f1(preds, test_labels, classes))
 
     return {
         "metrics": {k_: np.asarray(v) for k_, v in rows.items()},
@@ -371,9 +289,11 @@ def simulate(
     }
 
 
-def eval_f1(model: dict, params: dict, data: Dict[str, Any]) -> float:
-    """Macro-F1 of ``params`` on the test set, by the reference's forward."""
-    net = Model(model, REFERENCE, 0.0)
+def eval_f1(family: ModuleType, model: dict, params: dict, data: Dict[str, Any],
+            frozen: Any = None) -> float:
+    """Macro-F1 of ``params`` (the reference's flat leaves) on the test set,
+    by the reference model's forward."""
+    net = family.reference_model(model, REFERENCE, 0.0, frozen)
     p = {n: jnp.asarray(v, jnp.float32) for n, v in params.items()}
     preds = np.asarray(net.predict(p, data["test_images"]))
-    return macro_f1(preds, np.asarray(data["test_labels"]), model["num_classes"])
+    return macro_f1(preds, np.asarray(data["test_labels"]), family.num_classes(model))

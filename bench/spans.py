@@ -102,11 +102,11 @@ def scope_s(ops: Ops, paths: Dict[str, str], scopes: Iterable[str], window: tr.I
 def chunk_op_paths(cell) -> Dict[str, str]:
     """:func:`hlo_op_paths` of the cell's jitted chunk (the traffic's
     ``modules.epoch`` executable), compiled for the default device from
-    abstract arguments of the shapes a call gives it."""
+    abstract arguments of the shapes a call gives it (the backend, which
+    holds any frozen weights of the family, made from seed 0)."""
     import jax
 
     from bench import harness
-    from bench.data import make_dataset
     from repro.core import simulator
 
     if cell.traffic["entry"] != "run_simulation":
@@ -116,10 +116,11 @@ def chunk_op_paths(cell) -> Dict[str, str]:
     every = max(1, cfg.eval_every)
     if horizon > every and horizon % every:
         raise ValueError("a call of two chunk lengths runs two chunk executables")
-    be = harness.backend(cell)
+    be = harness.backend(cell, harness.make_frozen(cell, 0))
     fn = simulator.chunk_program(cfg, be, bool(cell.traffic.get("use_kernel", False)))
     carry = jax.eval_shape(lambda: simulator.init_carry(cfg, be))
-    data = jax.eval_shape(lambda: make_dataset(0, cell.config["data"], cell.config["model"]))
+    data = jax.eval_shape(lambda: cell.family.make_dataset(0, cell.config["data"],
+                                                           cell.config["model"]))
     ts = jax.eval_shape(lambda: jax.numpy.arange(min(every, horizon)))
     key = "jax_compilation_cache_include_metadata_in_key"
     saved = getattr(jax.config, key)

@@ -1,5 +1,6 @@
-"""The harness finds every configuration, cell and metric by name, takes new
-ones from new files alone, and refuses to run without a TPU."""
+"""The harness finds every configuration, model family, cell and metric by
+name, takes new ones from new files alone, and refuses to run without a
+TPU."""
 from __future__ import annotations
 
 import json
@@ -12,7 +13,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness
+from bench import compare, harness, models
 from bench.tests import tiny
 
 REPO = tiny.REPO
@@ -25,8 +26,8 @@ def test_every_cell_loads_by_name(workload):
     cell = harness.load_cell(REPO, workload)
     assert cell.traffic["entry"] == "run_simulation"
     assert cell.traffic["horizon"] % cell.config["sim"]["eval_every"] == 0
-    assert set(cell.limits["limits"]) <= {"int_mismatch", "probe_m_gap", "age_level_gap",
-                                           "fedavg_gap", "param_change_gap", "f1_gap"}
+    assert set(cell.limits["limits"]) <= set(compare.NUMBERS) | set(
+        getattr(cell.family, "NUMBERS", ()))
     assert cell.limits["limits"]["int_mismatch"] == 0
     for trace in (False, True):
         for m in cell.metrics(trace):
@@ -37,15 +38,13 @@ def test_every_cell_loads_by_name(workload):
 
 
 def test_configs_are_the_program_model():
-    from repro.configs.cifar_cnn import CONFIG
-
+    """Each configuration is the model its family's program builds, by the
+    family's own check (the CNN: ``cifar_cnn.CONFIG``)."""
     for c in SPEC["configs"]:
         cfg = json.loads((REPO / c["file"]).read_text())
-        m = cfg["model"]
-        assert (m["image_size"], m["in_channels"], m["num_classes"]) == (
-            CONFIG.image_size, CONFIG.in_channels, CONFIG.num_classes)
-        assert tuple(m["conv_channels"]) == CONFIG.conv_channels
-        assert tuple(m["fc_dims"]) == CONFIG.fc_dims
+        family = models.load(REPO / "bench", cfg["model"])
+        if hasattr(family, "check_model"):
+            family.check_model(cfg["model"])
         assert cfg["data"]["num_clients"] == cfg["sim"]["num_clients"]
         assert set(c["reduced"]) == set(cfg["reduced"])
 
@@ -82,6 +81,60 @@ def test_new_cell_and_metric_come_from_new_files(tmp_path):
     assert result["metrics"]["fixture_calls"]["value"] == result["attempted"] >= 1
     assert "driver_compile_s_per_call" in result["metrics"]
     assert all(m["name"] != "fixture_calls" for m in SPEC["per_layer"])  # the repo's copy is untouched
+
+
+@pytest.mark.parametrize("family,error", [
+    (None, "set model.family to the name of a module in bench/models/"),
+    ("transformer", "bench/models/transformer.py is missing"),
+])
+def test_config_without_a_family_module_is_refused(tmp_path, family, error):
+    """A configuration that names no family, or one with no module, fails
+    when its cell is loaded, naming where the module belongs."""
+    model = {k: v for k, v in tiny.MODEL.items() if k != "family"}
+    if family:
+        model["family"] = family
+    root = tiny.make_root(tmp_path)
+    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(
+        {"name": "tiny", "model": model, "data": tiny.DATA, "sim": tiny.SIM}))
+    with pytest.raises((ValueError, FileNotFoundError), match=re.escape(error)):
+        harness.load_cell(root, "tiny_vaoi")
+
+
+@pytest.mark.parametrize("policy,compact", [("vaoi", "auto"), ("fedavg", False)])
+def test_new_family_comes_from_a_new_file(tmp_path, policy, compact):
+    """A second model family, added to a copy of the benchmark as one new
+    file under ``bench/models/`` with a configuration, traffic and limits of
+    its own, runs its cell through the harness with ``correct`` true; the
+    number it adds to the comparison is held to its limit."""
+    root = tiny.make_root(tmp_path, policy=policy, compact=compact, model=tiny.MLP)
+    assert not (REPO / "bench" / "models" / "mlp.py").exists()
+    cell = harness.load_cell(root, f"tiny_{policy}")
+    result = harness.run(cell, 2**31 + 11, 0.2, False, 0.0, require_chip=False)
+    assert result["correct"], result["checks"]
+    assert 0 <= result["checks"]["frozen_gap"]["value"] <= 1e-5
+    assert result["info"]["lockstep_epochs"] >= 1
+
+
+def test_calibration_pass_on_the_tiny_cell(tmp_path, capsys):
+    """One pass of ``bench/calibrate.py`` over the tiny cell on the CPU:
+    the program's reading is under the tiny limits, the control's (the
+    family's model in bfloat16) and a planted fault's are not."""
+    from types import SimpleNamespace
+
+    from bench import calibrate
+
+    cell = harness.load_cell(tiny.make_root(tmp_path), "tiny_vaoi")
+    args = SimpleNamespace(program_seeds="3", control_seeds="4", faults="unchanged_training",
+                           fault_seeds="5", m_seeds="6", trace_out="")
+    assert calibrate.calibrate(cell, args) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines() if x.startswith("{")]
+    by_kind = {x["kind"]: x for x in lines}
+    assert set(by_kind) == {"program", "control", "fault:unchanged_training", "m_gap"}
+    limits = cell.limits["limits"]
+    assert compare.judge(by_kind["program"], limits)[0]
+    assert not compare.judge(by_kind["control"], limits)[0]
+    assert not compare.judge(by_kind["fault:unchanged_training"], limits)[0]
+    assert by_kind["m_gap"]["max_gap"] < 1e-4
 
 
 def _run(cwd: Path, env: dict) -> subprocess.CompletedProcess:

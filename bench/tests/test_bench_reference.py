@@ -1,5 +1,5 @@
 """The plain reference against the simulator's epoch body, on the CPU at a
-tiny size, and the benchmark's data generator against the program's.
+tiny size, and the CNN family's data generator against the program's.
 
 On the CPU the convolutions run in float32, so the simulator and the
 reference make the same decisions: integer dynamics and VAoI ages must be
@@ -14,23 +14,18 @@ import numpy as np
 import pytest
 
 from bench import compare, reference
-from bench.data import make_dataset
+from bench.calibrate import as_program
+from bench.models import cnn
 from bench.tests import tiny
 
 EPOCHS = 12
 
 
 def _program(policy, compact, seed, data):
-    from repro.configs.cifar_cnn import CNNConfig
     from repro.core import EHFLConfig, run_simulation
-    from repro.fl import cnn_backend
 
-    m = tiny.MODEL
-    be = cnn_backend(CNNConfig(image_size=m["image_size"], conv_channels=tuple(m["conv_channels"]),
-                               fc_dims=tuple(m["fc_dims"])))
-    s = dict(tiny.SIM)
-    cfg = EHFLConfig(**s, epochs=EPOCHS, policy=policy, compact=compact, seed=seed)
-    return run_simulation(cfg, be, data)
+    cfg = EHFLConfig(**tiny.SIM, epochs=EPOCHS, policy=policy, compact=compact, seed=seed)
+    return run_simulation(cfg, cnn.program_backend(tiny.MODEL), data)
 
 
 @pytest.mark.parametrize("policy,compact", [
@@ -38,10 +33,10 @@ def _program(policy, compact, seed, data):
 ])
 def test_reference_matches_epoch_body(policy, compact):
     seed = 5
-    data = make_dataset(3, tiny.DATA, tiny.MODEL)
+    data = cnn.make_dataset(3, tiny.DATA, tiny.MODEL)
     out = _program(policy, compact, seed, data)
     pm = jax.tree.map(np.asarray, out["metrics"])
-    ref = reference.simulate(tiny.SIM, tiny.MODEL, data, seed, EPOCHS, policy)
+    ref = reference.simulate(tiny.SIM, cnn, tiny.MODEL, data, seed, EPOCHS, policy)
     rm = ref["metrics"]
     for k in ("n_started", "n_uploaded", "energy"):
         np.testing.assert_array_equal(pm[k], rm[k], err_msg=k)
@@ -63,36 +58,36 @@ def test_lockstep_follows_the_witness():
     """An ambiguous decision is settled the program's way when the age sum
     pins it down, and ends the lockstep when it cannot; under VAoI the
     lockstep ends at the first epoch after a training."""
-    data = make_dataset(3, tiny.DATA, tiny.MODEL)
-    base = reference.simulate(tiny.SIM, tiny.MODEL, data, 5, 6, "vaoi")
+    data = cnn.make_dataset(3, tiny.DATA, tiny.MODEL)
+    simulate = lambda **kw: reference.simulate(tiny.SIM, cnn, tiny.MODEL, data, 5, 6, "vaoi", **kw)
+    base = simulate()
     sums = base["metrics"]["age_sum"]
     first = int(np.flatnonzero(base["metrics"]["n_started"])[0])
-    held = reference.simulate(tiny.SIM, tiny.MODEL, data, 5, 6, "vaoi", witness=sums, margin=1e-6)
+    held = simulate(witness=sums, margin=1e-6)
     assert held["t_stop"] == first + 1 and held["witness_faults"] == 0
     # every free client ambiguous, and a sum (1 of the 5 at epoch 0, when all
     # ages are 0) that no common decision gives: the lockstep ends, no fault
     split = sums.copy()
     split[0] = 1
-    wide = reference.simulate(tiny.SIM, tiny.MODEL, data, 5, 6, "vaoi", witness=split, margin=10.0)
+    wide = simulate(witness=split, margin=10.0)
     assert wide["t_stop"] == 0 and wide["witness_faults"] == 0
     bad = sums.copy()
     bad[first] += 100
-    off = reference.simulate(tiny.SIM, tiny.MODEL, data, 5, 6, "vaoi", witness=bad, margin=1e-6)
+    off = simulate(witness=bad, margin=1e-6)
     assert off["witness_faults"] == 1 and off["t_stop"] == first
 
 
 def test_control_fails_the_comparison():
     """The reference in bfloat16, in the program's place, reads over the
     tiny cell's limits."""
-    data = make_dataset(4, tiny.DATA, tiny.MODEL)
+    data = cnn.make_dataset(4, tiny.DATA, tiny.MODEL)
     n = tiny.SIM["num_clients"]
-    c = reference.simulate(tiny.SIM, tiny.MODEL, data, 4, EPOCHS, "vaoi", numerics=reference.CONTROL)
-    got = {"metrics": {**c["metrics"], "n_delivered": c["metrics"]["n_uploaded"], "f1": c["f1"]},
-           "global_params": c["global_params"], "msg_params": c["msg_params"],
-            "final": c["final"]}
-    ref = reference.simulate(tiny.SIM, tiny.MODEL, data, 4, EPOCHS, "vaoi",
+    c = reference.simulate(tiny.SIM, cnn, tiny.MODEL, data, 4, EPOCHS, "vaoi",
+                           numerics=reference.CONTROL)
+    ref = reference.simulate(tiny.SIM, cnn, tiny.MODEL, data, 4, EPOCHS, "vaoi",
                              witness=compare.age_sums(c["metrics"]["avg_age"], n), margin=1e-4)
-    nums = compare.numbers(got, ref, reference.eval_f1(tiny.MODEL, c["global_params"], data), n)
+    f1 = reference.eval_f1(cnn, tiny.MODEL, c["global_params"], data)
+    nums = compare.numbers(as_program(c), ref, f1, n)
     ok, _ = compare.judge(nums, {"int_mismatch": 0, "probe_m_gap": 1e-6, "param_change_gap": 1e-4})
     assert not ok, nums
 
@@ -104,7 +99,7 @@ def test_data_copy_matches_the_program_generator():
     from repro.data import make_federated_dataset
 
     d = tiny.DATA
-    ours = make_dataset(11, d, tiny.MODEL)
+    ours = cnn.make_dataset(11, d, tiny.MODEL)
     theirs = make_federated_dataset(
         jax.random.PRNGKey(11), num_clients=d["num_clients"],
         samples_per_client=d["samples_per_client"], alpha=d["alpha"], test_size=d["test_size"],
@@ -125,8 +120,66 @@ def test_sharded_generator_matches_unsharded():
     n = len(devs)
     data_cfg = {**tiny.DATA, "num_clients": 4 * n}
     mesh = Mesh(np.array(devs), ("data",))
-    sharded = make_dataset(9, data_cfg, tiny.MODEL, sharding=NamedSharding(mesh, PartitionSpec("data")))
-    plain = make_dataset(9, data_cfg, tiny.MODEL)
+    sharded = cnn.make_dataset(9, data_cfg, tiny.MODEL,
+                               sharding=NamedSharding(mesh, PartitionSpec("data")))
+    plain = cnn.make_dataset(9, data_cfg, tiny.MODEL)
     assert len(sharded["images"].sharding.device_set) == n
     for k in plain:
         np.testing.assert_array_equal(np.asarray(sharded[k]), np.asarray(plain[k]), err_msg=k)
+
+
+# The generator as it stood in ``bench/data.py`` before the model families,
+# kept here to hold ``cnn.make_dataset`` to it bit for bit.
+def _old_prototypes(key, num_classes, image_size, channels):
+    k1, _ = jax.random.split(key)
+    coarse = jax.random.normal(k1, (num_classes, 8, 8, channels)) * 1.5
+    return jax.image.resize(coarse, (num_classes, image_size, image_size, channels), "bilinear")
+
+
+def _old_label_partition(key, num_clients, samples_per_client, num_classes, alpha):
+    import jax.numpy as jnp
+
+    k1, k2 = jax.random.split(key)
+    props = jax.random.dirichlet(k1, jnp.full((num_classes,), alpha), (num_clients,))
+    labels = jax.vmap(
+        lambda k, p: jax.random.choice(k, num_classes, (samples_per_client,), p=p)
+    )(jax.random.split(k2, num_clients), props)
+    return labels.astype(jnp.int32)
+
+
+def _old_generate(key, *, num_clients, samples_per_client, num_classes, image_size,
+                  channels, alpha, test_size, noise):
+    import jax.numpy as jnp
+
+    kp, kl, kx, kt = jax.random.split(key, 4)
+    protos = _old_prototypes(kp, num_classes, image_size, channels)
+    labels = _old_label_partition(kl, num_clients, samples_per_client, num_classes, alpha)
+    eps = jax.random.normal(kx, (num_clients, samples_per_client, image_size, image_size, channels))
+    test_labels = (jnp.arange(test_size) % num_classes).astype(jnp.int32)
+    test_eps = jax.random.normal(kt, (test_size, image_size, image_size, channels))
+    return {
+        "images": protos[labels] + noise * eps,
+        "labels": labels,
+        "test_images": protos[test_labels] + noise * test_eps,
+        "test_labels": test_labels,
+    }
+
+
+def _old_make_dataset(seed, data_cfg, model_cfg):
+    kw = dict(num_clients=data_cfg["num_clients"],
+              samples_per_client=data_cfg["samples_per_client"],
+              num_classes=model_cfg["num_classes"], image_size=model_cfg["image_size"],
+              channels=model_cfg["in_channels"], alpha=data_cfg["alpha"],
+              test_size=data_cfg["test_size"], noise=data_cfg["noise"])
+    return jax.jit(lambda key: _old_generate(key, **kw))(jax.random.PRNGKey(seed))
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 5])
+def test_family_dataset_is_the_old_generator(seed):
+    """The CNN family's dataset is, bit for bit, the benchmark's generator
+    as it stood before it moved into ``bench/models/cnn.py``."""
+    ours = cnn.make_dataset(seed, tiny.DATA, tiny.MODEL)
+    old = _old_make_dataset(seed, tiny.DATA, tiny.MODEL)
+    assert ours.keys() == old.keys()
+    for k in old:
+        np.testing.assert_array_equal(np.asarray(ours[k]), np.asarray(old[k]), err_msg=k)

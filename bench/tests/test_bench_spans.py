@@ -176,7 +176,6 @@ def test_chunk_op_paths_are_those_of_the_run(tiny_cell):
     setting it flips is put back."""
     import jax
     import jax.numpy as jnp
-    from bench.data import make_dataset
     from repro.core import simulator
 
     key = "jax_compilation_cache_include_metadata_in_key"
@@ -185,7 +184,7 @@ def test_chunk_op_paths_are_those_of_the_run(tiny_cell):
     assert getattr(jax.config, key) == before
     cfg = harness.sim_config(tiny_cell, 3, tiny_cell.traffic["horizon"])
     be = harness.backend(tiny_cell)
-    data = make_dataset(3, tiny_cell.config["data"], tiny_cell.config["model"])
+    data = tiny_cell.family.make_dataset(3, tiny_cell.config["data"], tiny_cell.config["model"])
     ran = simulator.chunk_program(cfg, be).lower(
         simulator.init_carry(cfg, be), jnp.arange(cfg.eval_every), data["images"], data["labels"])
     assert spans.hlo_op_paths(ran.compile().as_text()) == paths
@@ -246,10 +245,20 @@ def test_new_readers_fall_silent_on_a_program_without_spans(metric, chip_trace):
     assert harness.load_reader(cell, metric)(ctx) is None
 
 
-def test_new_metrics_in_a_traced_run(tmp_path):
+def test_new_metrics_in_a_traced_run(tmp_path, monkeypatch):
     """A traced CPU run of the tiny cell with the new metrics listed for it:
-    the retrace counter reads one trace per call; the device metrics find no
-    device ops on the CPU and are left out."""
+    the retrace counter reads the program's own count of chunk traces over
+    the window's calls, at most one per call (none where the program keeps
+    its chunk across calls); the device metrics find no device ops on the
+    CPU and are left out."""
+    runs = []
+
+    class Kept(harness.Events):
+        def __init__(self):
+            super().__init__()
+            runs.append(self)
+
+    monkeypatch.setattr(harness, "Events", Kept)
     root = tiny.make_root(tmp_path)
     spec = json.loads((root / "BENCHMARK.json").read_text())
     for m in spec["per_layer"]:
@@ -259,5 +268,7 @@ def test_new_metrics_in_a_traced_run(tmp_path):
     result = harness.run(harness.load_cell(root, "tiny_vaoi"), 2**31 + 7, 0.5, True, 0.0,
                          require_chip=False)
     assert result["correct"], result["checks"]
-    assert result["metrics"]["chunk_traces_per_call"]["value"] == 1.0
+    value = result["metrics"]["chunk_traces_per_call"]["value"]
+    assert value == runs[-1].counts.get(spans.CHUNK_TRACE_EVENT, 0) / result["attempted"]
+    assert 0.0 <= value <= 1.0
     assert not set(NEW_METRICS[1:]) & set(result["metrics"])

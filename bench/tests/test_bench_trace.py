@@ -9,6 +9,7 @@ import pytest
 
 from bench import flops, peaks
 from bench import trace as tr
+from bench.models import cnn
 from bench.tests import tiny
 
 FIXTURE = Path(__file__).with_name("data") / "run_simulation_small.xplane.pb.gz"
@@ -22,20 +23,54 @@ def test_forward_flops_hand_count():
              2 * 16 * 16 * 9 * 32 * 64, 2 * 16 * 16 * 9 * 64 * 64,
              2 * 8 * 8 * 9 * 64 * 128, 2 * 8 * 8 * 9 * 128 * 128]
     dense = [2 * 4 * 4 * 128 * 256, 2 * 256 * 128, 2 * 128 * 10]
-    assert flops.forward_flops(PAPER["model"]) == sum(convs) + sum(dense) == 78_383_616
-    assert flops.train_flops(PAPER["model"]) == 3 * 78_383_616
+    assert cnn.forward_flops(PAPER["model"]) == sum(convs) + sum(dense) == 78_383_616
+    assert cnn.feature_flops(PAPER["model"]) == 78_383_616
+    assert cnn.train_flops(PAPER["model"]) == 3 * 78_383_616
 
 
 def test_window_flops_counts_only_started_clients():
     m, s, d = PAPER["model"], PAPER["sim"], PAPER["data"]
-    fwd = flops.forward_flops(m)
+    fwd = cnn.forward_flops(m)
     batch = d["samples_per_client"] // s["kappa"]
-    got = flops.window_flops(m, s, d, "vaoi", n_started=7, epochs=3, evals=2)
+    got = flops.window_flops(cnn, m, s, d, "vaoi", n_started=7, epochs=3, evals=2)
     want = (7 * s["kappa"] * batch * 4 * fwd + 3 * s["num_clients"] * s["probe_size"] * fwd
             + 2 * d["test_size"] * fwd)
     assert got == want
-    assert flops.window_flops(m, s, d, "fedavg", n_started=7, epochs=3, evals=0) == (
+    assert flops.window_flops(cnn, m, s, d, "fedavg", n_started=7, epochs=3, evals=0) == (
         7 * s["kappa"] * batch * 3 * fwd)
+
+
+def _old_forward_flops(model):
+    """``bench/flops.py``'s count as it stood before the model families."""
+    side, cin, total = model["image_size"], model["in_channels"], 0
+    for i, cout in enumerate(model["conv_channels"]):
+        total += 2 * side * side * 9 * cin * cout
+        cin = cout
+        if i % 2 == 1:
+            side //= 2
+    dims = [side * side * cin, *model["fc_dims"], model["num_classes"]]
+    return total + sum(2 * a * b for a, b in zip(dims, dims[1:]))
+
+
+def _old_window_flops(model, sim, data, policy, *, n_started, epochs, evals):
+    fwd = _old_forward_flops(model)
+    kappa = sim["kappa"]
+    batch = max(1, data["samples_per_client"] // kappa)
+    flops = n_started * kappa * batch * 3 * fwd
+    if policy.startswith("vaoi"):
+        flops += n_started * kappa * batch * fwd
+        flops += epochs * sim["num_clients"] * sim["probe_size"] * fwd
+    return float(flops + evals * data["test_size"] * fwd)
+
+
+@pytest.mark.parametrize("policy", ["vaoi", "fedavg"])
+def test_window_flops_is_the_old_count(policy):
+    """``cnn_paper_n100``'s window count through the CNN family equals the
+    count as it was computed before the families, for both policies."""
+    m, s, d = PAPER["model"], PAPER["sim"], PAPER["data"]
+    kw = dict(n_started=1234, epochs=900, evals=90)
+    assert flops.window_flops(cnn, m, s, d, policy, **kw) == _old_window_flops(m, s, d, policy,
+                                                                                **kw)
 
 
 def test_peaks_known_and_unknown_device():
